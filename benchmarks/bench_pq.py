@@ -18,7 +18,7 @@ Gates (CI smoke lane):
     trace (``functional.TRACE_COUNTS``);
   * **kernel parity** — the Pallas ADC kernel returns bit-identical ids
     to the XLA gather-fold through the full search path (reduced batch:
-    interpret mode emulates every DMA in this container).
+    on a CPU backend interpret mode emulates every DMA).
 
     PYTHONPATH=src python benchmarks/bench_pq.py [--smoke]
 """

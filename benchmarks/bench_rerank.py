@@ -16,9 +16,9 @@ the steady-state serving hot loop):
     [b, block, d] gathered chunk.
   * **kernel**       — the fused Pallas kernel path (``rerank_kernel``
     build flag): gather DMA'd row-by-row into VMEM scratch.  Timed on a
-    reduced query batch — in this container it runs in INTERPRET mode
-    (every DMA is emulated), so its wall-clock is a correctness proxy, not
-    a perf claim; the perf claim on CPU is stream_fold's.
+    reduced query batch — on a CPU backend it runs in interpret mode
+    (every DMA is emulated), so its wall-clock there is a correctness
+    proxy, not a perf claim.
 
 Gates (CI smoke lane):
 

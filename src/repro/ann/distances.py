@@ -21,16 +21,21 @@ import jax.numpy as jnp
 METRICS = ("euclidean", "angular", "hamming")
 
 
+def _cross(Q: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
+    """Q @ X.T in full fp32: at default precision a TPU makes one bf16 pass
+    over f32 operands, which reorders near neighbours."""
+    return jnp.matmul(Q, X.T, precision=jax.lax.Precision.HIGHEST)
+
+
 def sq_l2_matrix(Q: jnp.ndarray, X: jnp.ndarray,
                  x_sqnorm: jnp.ndarray | None = None) -> jnp.ndarray:
     """Squared L2 distances via the MXU-friendly expansion
-    ||q||^2 - 2 q.x + ||x||^2, fp32 accumulation."""
+    ||q||^2 - 2 q.x + ||x||^2, fp32 products and accumulation."""
     Q = Q.astype(jnp.float32)
     X = X.astype(jnp.float32)
     qn = jnp.sum(Q * Q, axis=1, keepdims=True)
     xn = jnp.sum(X * X, axis=1)[None, :] if x_sqnorm is None else x_sqnorm[None, :]
-    cross = Q @ X.T
-    return jnp.maximum(qn - 2.0 * cross + xn, 0.0)
+    return jnp.maximum(qn - 2.0 * _cross(Q, X) + xn, 0.0)
 
 
 def angular_matrix(Q: jnp.ndarray, X: jnp.ndarray,
@@ -40,7 +45,7 @@ def angular_matrix(Q: jnp.ndarray, X: jnp.ndarray,
     if not normalized:
         Q = Q / jnp.maximum(jnp.linalg.norm(Q, axis=1, keepdims=True), 1e-12)
         X = X / jnp.maximum(jnp.linalg.norm(X, axis=1, keepdims=True), 1e-12)
-    return 1.0 - Q @ X.T
+    return 1.0 - _cross(Q, X)
 
 
 def hamming_matrix(Q: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
@@ -58,7 +63,7 @@ def masked_rows_to(X: jnp.ndarray, q: jnp.ndarray, ids: jnp.ndarray,
     """
     x = X[jnp.maximum(ids, 0)]
     if metric == "angular":
-        d = 1.0 - x @ q
+        d = 1.0 - jnp.matmul(x, q, precision=jax.lax.Precision.HIGHEST)
     else:
         diff = x - q[None, :]
         d = jnp.sum(diff * diff, axis=-1)
@@ -109,7 +114,8 @@ def _rows_kernel(q, train, idx, *, metric):
         cn = cand / jnp.maximum(
             jnp.linalg.norm(cand, axis=2, keepdims=True), 1e-12)
         return 1.0 - jnp.einsum("bd,bkd->bk", qn.astype(jnp.float32),
-                                cn.astype(jnp.float32))
+                                cn.astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST)
     if metric == "hamming":
         x = jax.lax.bitwise_xor(cand.astype(jnp.uint32),
                                 q[:, None, :].astype(jnp.uint32))

@@ -65,7 +65,12 @@ def bruteforce_build(X: np.ndarray, *, metric: str = "hamming",
                      corpus_block: int = 65536,
                      query_block: int = 4096) -> IndexState:
     X = np.asarray(X, np.uint32)
-    return IndexState("BruteForceHamming", metric, {"X": jnp.asarray(X)}, {
+    arrays = {"X": jnp.asarray(X)}
+    if backend == "pallas":
+        from repro.kernels.hamming import word_major
+
+        arrays["XT"] = word_major(arrays["X"])    # the kernel's layout
+    return IndexState("BruteForceHamming", metric, arrays, {
         "n": int(X.shape[0]), "backend": backend,
         "streaming": bool(streaming), "corpus_block": int(corpus_block),
         "query_block": int(query_block),
@@ -78,7 +83,7 @@ def bruteforce_search(state: IndexState, Q, *, k: int):
     if state.stat("backend") == "pallas":
         from repro.kernels.hamming import ops as hops
 
-        return hops.hamming_topk(Q, state["X"], k=k)
+        return hops.hamming_topk(Q, state["XT"], k=k)
     d = _popcount_matrix(Q, state["X"])
     return topk_smallest(d.astype(jnp.float32), k)
 
@@ -125,9 +130,11 @@ class BruteForceHamming(FunctionalANN):
         if self.backend == "pallas":
             from repro.kernels.hamming import ops as hops
 
+            XT = self._state["XT"]
+
             def corpus_chunk(Qb):
                 def chunk(s, size):
-                    v, i = hops.hamming_topk(Qb, X[s:s + size],
+                    v, i = hops.hamming_topk(Qb, XT[:, s:s + size],
                                              k=min(k, size))
                     return v.astype(jnp.float32), i + s
                 return chunk

@@ -572,7 +572,7 @@ def make_sharded_topk(mesh: Mesh, shard_axes: Sequence[str], k: int,
     tree (:func:`repro.dist.collectives.tree_merge_topk`, full-precision
     root tiebreak) instead of the old flat f32 ``all_gather``.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist import wire
@@ -593,4 +593,4 @@ def make_sharded_topk(mesh: Mesh, shard_axes: Sequence[str], k: int,
 
     in_specs = (P(), P(axes), P(axes), P(axes))
     return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=(P(), P()), check_rep=False))
+                             out_specs=(P(), P()), check_vma=False))

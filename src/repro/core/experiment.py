@@ -11,11 +11,14 @@ Two phases per algorithm instance:
      clock via ``get_batch_results``).
 
 Isolation: the paper runs every instance in its own Docker container.  Here
-each instance can run in a forked subprocess (``isolated=True``) — same
+each instance can run in a spawned subprocess (``isolated=True``) — same
 crash/timeout containment and clean teardown semantics, no Docker dependency
 (the paper's "local mode").  Memory use of the index is measured as the
 RSS delta around fit() in that subprocess, alongside the structural
-``index_size()``.
+``index_size()``.  An accelerator belongs to one process at a time, so the
+parent of an isolated run must not hold one: given a dataset *name*, the
+child loads the dataset itself, and a parent that already holds a non-CPU
+backend is refused (:func:`check_can_isolate`).
 """
 
 from __future__ import annotations
@@ -24,13 +27,13 @@ import dataclasses
 import multiprocessing as mp
 import time
 import traceback
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
 from repro.core.config import Definition, instantiate
 from repro.core.metrics import RunRecord
-from repro.data.datasets import Dataset
+from repro.data.datasets import Dataset, get_dataset
 
 
 @dataclasses.dataclass
@@ -65,12 +68,18 @@ def _rss_kb() -> float:
 
 def run_definition(
     definition: Definition,
-    dataset: Dataset,
+    dataset: Union[Dataset, str],
     settings: ExperimentSettings,
 ) -> List[RunRecord]:
-    """Run one algorithm instance through the full experiment loop."""
+    """Run one algorithm instance through the full experiment loop.
+
+    ``dataset`` may be a registered dataset name: the process that runs
+    the instance (the child, when isolated) then loads it itself.
+    """
     if settings.isolated:
         return _run_isolated(definition, dataset, settings)
+    if isinstance(dataset, str):
+        dataset = get_dataset(dataset)
     return _run_local(definition, dataset, settings)
 
 
@@ -274,25 +283,67 @@ def _distances_for(dataset: Dataset, neighbors: np.ndarray) -> np.ndarray:
 # subprocess isolation (the Docker-container analogue)
 # --------------------------------------------------------------------------
 
-def _child(conn, definition, dataset, settings):
+def held_backend() -> Optional[str]:
+    """Platform of the JAX backend this process has initialized, or None
+    if it has initialized none (importing jax initializes nothing)."""
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    import jax
+
+    return jax.default_backend()
+
+
+def check_can_isolate(backend: Optional[str]) -> None:
+    """Refuse isolation from a parent that holds an accelerator.
+
+    An isolated child needs the device, and a device belongs to the one
+    process that initialized its backend: a child spawned by such a parent
+    fails or hangs waiting for it.  ``backend`` is :func:`held_backend`'s
+    answer; a parent on the CPU backend (or on none) may isolate.
+    """
+    if backend is not None and backend != "cpu":
+        raise RuntimeError(
+            f"isolated runs need the parent process to stay off the "
+            f"accelerator, but this process already holds the {backend!r} "
+            f"backend, so a child process cannot get the device; run "
+            f"without isolation, or start the isolated run from a process "
+            f"that has not used JAX (pass the dataset by name)")
+
+
+def _child(conn, target, args, cache_on):
     try:
-        settings = dataclasses.replace(settings, isolated=False)
-        records = run_definition(definition, dataset, settings)
-        conn.send(("ok", records))
+        if cache_on:
+            from repro.core import compile_cache
+
+            compile_cache.enable()
+        conn.send(("ok", target(*args)))
     except Exception:
         conn.send(("error", traceback.format_exc()))
     finally:
         conn.close()
 
 
-def _run_isolated(definition, dataset, settings) -> List[RunRecord]:
+def run_in_child(target: Callable, args: tuple, *, label: str,
+                 timeout: Optional[float] = None):
+    """``target(*args)`` in a spawned child process; returns its result.
+
+    Raises ``RuntimeError`` naming ``label`` if the child raised or died
+    without reporting, and ``TimeoutError`` (after terminating it) if it
+    ran past ``timeout`` seconds.
+    """
+    import jax
+
+    check_can_isolate(held_backend())
+    # the child compiles into the parent's persistent cache, if it has one
+    cache_on = bool(jax.config.jax_compilation_cache_dir)
     # spawn, not fork: jax's internal threads deadlock forked children
     ctx = mp.get_context("spawn")
     parent, child = ctx.Pipe()
-    proc = ctx.Process(target=_child, args=(child, definition, dataset, settings))
+    proc = ctx.Process(target=_child, args=(child, target, args, cache_on))
     proc.start()
     child.close()
-    timeout = settings.timeout
     if parent.poll(timeout):
         # poll() also returns True when the pipe hits EOF — a child killed
         # mid-run (OOM, SIGKILL, hard crash in a C extension) closes the
@@ -302,18 +353,23 @@ def _run_isolated(definition, dataset, settings) -> List[RunRecord]:
         except EOFError:
             proc.join()
             raise RuntimeError(
-                f"isolated run of {definition.instance_name} died before "
-                f"reporting a result (exit code {proc.exitcode}; OOM kill "
-                f"or crash in native code?)") from None
+                f"isolated run of {label} died before reporting a result "
+                f"(exit code {proc.exitcode}; OOM kill or crash in native "
+                f"code?)") from None
         proc.join()
         if status == "error":
-            raise RuntimeError(
-                f"isolated run of {definition.instance_name} failed:\n{payload}")
+            raise RuntimeError(f"isolated run of {label} failed:\n{payload}")
         return payload
     # Timeout exceeded: terminate the container-equivalent (paper §3.4:
     # "perform a blocking, timed wait on the container, and will terminate
     # it if the user-configurable timeout is exceeded").
     proc.terminate()
     proc.join()
-    raise TimeoutError(
-        f"{definition.instance_name} exceeded timeout of {timeout}s")
+    raise TimeoutError(f"{label} exceeded timeout of {timeout}s")
+
+
+def _run_isolated(definition, dataset, settings) -> List[RunRecord]:
+    local = dataclasses.replace(settings, isolated=False)
+    return run_in_child(run_definition, (definition, dataset, local),
+                        label=definition.instance_name,
+                        timeout=settings.timeout)

@@ -18,16 +18,23 @@ import time
 from pathlib import Path
 from typing import List, Optional, Sequence
 
+from repro.core import compile_cache
 from repro.core import config as config_mod
 from repro.core import results as results_mod
-from repro.core.experiment import ExperimentSettings, run_definition
+from repro.core.experiment import (ExperimentSettings, check_can_isolate,
+                                   held_backend, run_definition,
+                                   run_in_child)
 from repro.core.metrics import RunRecord
 from repro.core.plotting import ascii_frontier
-from repro.data.datasets import get_dataset
+from repro.data.datasets import cache_path, get_dataset
 
 
 DEFAULT_CONFIG = str(Path(__file__).resolve().parents[1]
                      / "configs" / "ann_default.yaml")
+
+
+def _cache_dataset(name: str) -> None:
+    get_dataset(name)          # builds and caches; returns nothing to pickle
 
 
 def run_benchmark(
@@ -44,6 +51,14 @@ def run_benchmark(
     query_block: Optional[int] = None,
     verbose: bool = True,
 ) -> List[RunRecord]:
+    if isolated:
+        # the parent stays off the device: a child builds (and caches) the
+        # dataset, whose ground truth runs on the device, and the parent
+        # reads only the cached numpy arrays
+        check_can_isolate(held_backend())
+        if not cache_path(dataset_name).exists():
+            run_in_child(_cache_dataset, (dataset_name,),
+                         label=f"dataset {dataset_name}")
     dataset = get_dataset(dataset_name)
     definitions = config_mod.get_definitions(
         config_source or DEFAULT_CONFIG,
@@ -62,7 +77,9 @@ def run_benchmark(
         label = definition.instance_name
         t0 = time.perf_counter()
         try:
-            records = run_definition(definition, dataset, settings)
+            # isolated children load the dataset by name themselves
+            records = run_definition(
+                definition, dataset_name if isolated else dataset, settings)
         except (TimeoutError, RuntimeError) as e:
             if verbose:
                 print(f"  [FAIL] {label}: {e}", file=sys.stderr)
@@ -93,6 +110,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                         "(fixed memory for arbitrarily large query sets)")
     args = p.parse_args(argv)
 
+    compile_cache.enable()
     records = run_benchmark(
         args.dataset, args.config, count=args.count, batch=args.batch,
         algorithms=args.algorithms, out_dir=args.out, isolated=args.isolated,

@@ -12,7 +12,8 @@ A dataset file contains — field-for-field the paper's HDF5 schema, stored as
 
 "By default, the framework fetches datasets on demand": here, on-demand means
 the synthetic builder runs (deterministically, seeded by name) the first time
-a dataset is requested and the file is cached under ``data_dir``.
+a dataset is requested and the file is cached under ``data_dir``
+(``$REPRO_DATA_DIR``, else ``repro_data`` in the temporary directory).
 """
 
 from __future__ import annotations
@@ -21,12 +22,14 @@ import dataclasses
 import json
 import os
 import re
+import tempfile
 from pathlib import Path
 from typing import Callable, Dict, Optional
 
 import numpy as np
 
-DEFAULT_DATA_DIR = Path(os.environ.get("REPRO_DATA_DIR", "/tmp/repro_data"))
+DEFAULT_DATA_DIR = Path(os.environ.get(
+    "REPRO_DATA_DIR", os.path.join(tempfile.gettempdir(), "repro_data")))
 GT_K = 100  # paper: "a list of the true nearest k=100 neighbours"
 
 
@@ -90,11 +93,13 @@ def register_dataset(pattern: str):
     return deco
 
 
-def get_dataset(name: str, data_dir: Optional[str | Path] = None) -> Dataset:
-    data_dir = Path(data_dir or DEFAULT_DATA_DIR)
-    cache = data_dir / f"{name}.npz"
-    if cache.exists():
-        return Dataset.load(cache)
+def cache_path(name: str, data_dir: Optional[str | Path] = None) -> Path:
+    """Where :func:`get_dataset` caches dataset ``name``."""
+    return Path(data_dir or DEFAULT_DATA_DIR) / f"{name}.npz"
+
+
+def build_dataset(name: str) -> Dataset:
+    """Run the registered builder for ``name`` (no cache involved)."""
     for pattern, builder in _BUILDERS.items():
         m = re.fullmatch(pattern, name)
         if m:
@@ -102,11 +107,21 @@ def get_dataset(name: str, data_dir: Optional[str | Path] = None) -> Dataset:
                 k: (int(v) if v is not None and v.isdigit() else v)
                 for k, v in m.groupdict().items()
             }
-            ds = builder(name=name, **kwargs)
-            ds.save(cache)
-            return ds
+            return builder(name=name, **kwargs)
     raise KeyError(f"unknown dataset {name!r}; known patterns: "
                    f"{list(_BUILDERS)}")
+
+
+def get_dataset(name: str, data_dir: Optional[str | Path] = None) -> Dataset:
+    """Dataset ``name`` from the cache, built and cached on a miss.  A
+    cache hit reads numpy arrays only; a build computes ground truth on
+    the default JAX device."""
+    cache = cache_path(name, data_dir)
+    if cache.exists():
+        return Dataset.load(cache)
+    ds = build_dataset(name)
+    ds.save(cache)
+    return ds
 
 
 def available_patterns():

@@ -3,7 +3,10 @@ k=100 neighbors + distances).
 
 Blocked brute force on device: query blocks x corpus blocks with a running
 top-k merge, so GT for n=10^6-scale corpora never materialises the full
-distance matrix.  This is the same merge used by the sharded serving path.
+distance matrix.  The corpus goes to the device once; every distance is
+computed at full fp32 precision (``repro.ann.distances`` runs its matmuls
+at ``Precision.HIGHEST``: a TPU's default precision would make one bf16
+pass and misorder near neighbours).
 """
 
 from __future__ import annotations
@@ -42,13 +45,14 @@ def exact_knn(
         neg, idx = jax.lax.top_k(-d, kk)
         return -neg, idx
 
+    Xd = [jnp.asarray(train[s:e]) for (s, e) in corpus_blocks]
     for qs in range(0, nq, query_block):
         qe = min(qs + query_block, nq)
         q = jnp.asarray(test[qs:qe])
         best_d = np.full((qe - qs, k), np.inf, np.float32)
         best_i = np.full((qe - qs, k), -1, np.int64)
-        for (s, e) in corpus_blocks:
-            d, i = block_topk(q, jnp.asarray(train[s:e]))
+        for (s, e), x in zip(corpus_blocks, Xd):
+            d, i = block_topk(q, x)
             d = np.asarray(d, np.float32)
             i = np.asarray(i, np.int64) + s
             # merge running top-k with this block's top-k

@@ -16,6 +16,8 @@ Each builder computes exact ground truth for k=100 (or n if smaller).
 
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 
 from repro.data.datasets import Dataset, GT_K, register_dataset
@@ -28,8 +30,15 @@ def _nq(n: int) -> int:
     return max(10, min(10_000, int(n * _NQ_FRACTION) or 10))
 
 
+def seed_for(name: str) -> int:
+    """The generator seed of dataset ``name``: a stable digest of the
+    name, the same in every process (``hash`` of a str is salted per
+    process)."""
+    return zlib.crc32(name.encode())
+
+
 def _seed(name: str) -> np.random.Generator:
-    return np.random.default_rng(abs(hash(name)) % (2**32))
+    return np.random.default_rng(seed_for(name))
 
 
 def _finish(name, train, test, metric, point_type="float", k=GT_K) -> Dataset:

@@ -20,7 +20,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.dist import wire
@@ -53,7 +53,7 @@ def sharded_embed_lookup(emb, tokens, mesh: Optional[Mesh] = None,
         return jax.lax.psum(out, axis)
 
     fn = shard_map(local, mesh=mesh, in_specs=(P(axis, None), P()),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return fn(emb, tokens)
 
 
@@ -147,7 +147,7 @@ def tree_merge_topk(vals, ids, *, axes: Sequence[str],
     own_vals, own_ids = vals, ids          # f32, for the exact_vals root
     # snap local values into wire precision so every fold compares in the
     # same (idempotent) domain regardless of merge grouping
-    vals = wire.decode(wire.encode(vals, codec, lo, hi), codec, lo, hi, ids)
+    vals = wire.snap(vals, codec, lo, hi, ids)
 
     for ax, S in reversed(live_axes):
         for stride, f in _axis_schedule(S, fan_in):

@@ -23,7 +23,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -72,7 +72,7 @@ def compress_gradients(grads, err_state, *, mesh: Optional[Mesh] = None,
         def allmean(x):
             fn = shard_map(lambda y: jax.lax.psum(y, red_axes) / size,
                            mesh=mesh, in_specs=P(), out_specs=P(),
-                           check_rep=False)
+                           check_vma=False)
             return fn(x)
 
         comp = jax.tree.map(allmean, comp)

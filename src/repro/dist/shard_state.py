@@ -28,7 +28,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.dist import wire
@@ -312,7 +312,7 @@ def sharded_search(state, Q, *, k: int, mesh: Optional[Mesh] = None,
             local, mesh=mesh,
             in_specs=(P(), (P(),) * len(plan.knob_names), P(axes),
                       (P(),) * n_rep, (P(axes),) * len(shard_names)),
-            out_specs=(P(), P()), check_rep=False)
+            out_specs=(P(), P()), check_vma=False)
 
         def outer(q, kv, ok, rep_t, shard_t):
             if prep_names:

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 
 #: distance bytes per wire entry, by codec (ids add ID_BYTES each).
@@ -95,6 +96,26 @@ def decode(w, codec: str, lo=None, hi=None, ids=None):
         span = jnp.maximum(hi - lo, 1e-30)
         val = lo + w.astype(jnp.float32) * (span / (_I8_LEVELS - 1))
         out = jnp.where(w == _I8_INF, jnp.inf, val)
+    if ids is not None:
+        out = jnp.where(ids < 0, jnp.inf, out)
+    return out
+
+
+def snap(d, codec: str, lo=None, hi=None, ids=None):
+    """f32 distances rounded to wire precision and kept f32: the values of
+    ``decode(encode(d))``, which every device must fold its own candidates
+    with, bit for bit what its peers decode from the wire.
+
+    bf16 rounds to nearest-even on the f32 bit pattern with integer ops.
+    A TPU build keeps floats inside a fusion at f32 where XLA allows
+    excess precision, so a f32 -> bf16 -> f32 convert pair there can leave
+    a device's own values unrounded while its peers receive them rounded;
+    the butterfly partners then fold different candidate sets."""
+    if codec != "bf16":
+        return decode(encode(d, codec, lo, hi), codec, lo, hi, ids)
+    u = jax.lax.bitcast_convert_type(d.astype(jnp.float32), jnp.uint32)
+    u = (u + 0x7FFF + ((u >> 16) & 1)) & jnp.uint32(0xFFFF0000)
+    out = jax.lax.bitcast_convert_type(u, jnp.float32)
     if ids is not None:
         out = jnp.where(ids < 0, jnp.inf, out)
     return out

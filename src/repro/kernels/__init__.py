@@ -4,7 +4,7 @@ Each kernel lives in its own subpackage with three files:
 
     <name>.py   pl.pallas_call + explicit BlockSpec VMEM tiling (TPU target)
     ops.py      jit'd public wrapper (shape padding, dtype plumbing,
-                interpret-mode switch for CPU validation)
+                interpret mode on a CPU backend, see ``interpret_mode``)
     ref.py      pure-jnp oracle the tests assert against
 
 Kernels:
@@ -13,10 +13,11 @@ Kernels:
                    accumulators, d-tiling, and query-block streaming so
                    nq and n are both unbounded by HBM (O(nq*k) output)
                    (supersedes the retired topk_scan kernel)
-    rerank_topk/   fused candidate rerank: scalar-prefetched row gather into
-                   VMEM scratch + distance + running unique-by-id top-k, so
-                   the [b, C, d] gathered candidate tensor never exists in
-                   HBM (every algorithm's verification hot path)
+    rerank_topk/   fused candidate rerank: row gather driven by per-tile
+                   SMEM id blocks into VMEM scratch + distance + running
+                   unique-by-id top-k, so the [b, C, d] gathered candidate
+                   tensor never exists in HBM (every algorithm's
+                   verification hot path)
     adc_scan/      compressed-domain ADC scan: per-query LUTs resident in
                    VMEM, packed uint8 codes streamed in blocks, distances
                    as one-hot x LUT matmuls on the MXU, running top-C fold
@@ -24,20 +25,22 @@ Kernels:
     hamming/       XOR + popcount distances over packed uint32 codes
     embedbag/      embedding-bag gather-reduce (recsys hot path)
     decode_attn/   single-token decode attention with online softmax
+
+``select.py`` holds the in-kernel top-k selects the scan kernels share.
 """
 
-import os
 
-# CPU container: kernels run in interpret mode.  On real TPU runtimes set
-# REPRO_PALLAS_INTERPRET=0.
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def interpret_mode(backend: str | None = None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode: only on a ``cpu``
+    backend, where there is no TPU to compile for.
 
+    Decided when the kernel is called, never at import: ``backend``
+    defaults to ``jax.default_backend()``.  On a TPU the kernel is
+    compiled by Mosaic or the call raises; nothing falls back to the
+    interpreter.
+    """
+    if backend is None:
+        import jax
 
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` across jax versions (renamed from
-    ``TPUCompilerParams`` in newer releases)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+        backend = jax.default_backend()
+    return backend == "cpu"

@@ -2,18 +2,22 @@
 the MXU, running top-C candidate fold in VMEM scratch.
 
 Per grid step a [bn, m] uint8 code block and the query tile's resident
-[bq, m, K] lookup tables (built ONCE per batch) meet in VMEM.  TPUs have
-no fast dynamic vector gather, so the per-candidate table lookup
+lookup tables (built ONCE per batch) meet in VMEM.  TPUs have no fast
+dynamic vector gather, so the per-candidate table lookup
 ``sum_j LUT[q, j, code[i, j]]`` is reformulated as a matmul the MXU can
-chew: one-hot(code block) contracted against the LUT tile over the
-(subspace, code) axes,
+chew: one-hot(code block) contracted against the LUT tile,
 
     d[q, i] = sum_{j, c} LUT[q, j, c] * onehot(codes[i, j])[c]
 
-chunked over the K axis so the [bn, m, kc] one-hot tensor stays inside a
-VMEM budget.  The one-hot entries are exactly 0/1, so each distance is a
-sum of the SAME m table entries the gather formulation reads — this is a
-lookup evaluated as arithmetic, not an approximation.
+chunked over the K axis so the one-hot tile stays inside a VMEM budget.
+The MXU contracts over one dimension, so the (subspace, code) pair is
+folded into one axis of width ``m * kc``: the wrapper lays the LUTs out as
+``[K / kc, b, m * kc]`` (column ``j * kc + c`` of chunk ``ci`` holds
+``LUT[q, j, ci * kc + c]``), and the kernel widens each code to the same
+columns with a 0/1 repeat matmul, ``codes @ E`` with
+``E[j, t] = (t // kc == j)``.  The one-hot entries are exactly 0/1, so
+each distance is a sum of the SAME m table entries the gather formulation
+reads — this is a lookup evaluated as arithmetic, not an approximation.
 
 Each block's (dist, row) pairs fold into a running per-query top-C
 accumulator via the shared ``merge_topk_unique_rounds`` (bit-identical to
@@ -36,11 +40,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-from repro.kernels.distance_topk.distance_topk import NEG_ONE
-from repro.kernels.rerank_topk.rerank_topk import merge_topk_unique_rounds
+from repro.kernels.select import NEG_ONE, merge_topk_unique_rounds
 
-_ONEHOT_BUDGET = 2 << 20    # [bn, m, kc] one-hot chunk VMEM bytes
+_ONEHOT_BUDGET = 2 << 20    # [bn, m * kc] one-hot chunk VMEM bytes
 
 
 def _pick_kc(bn: int, m: int, K: int,
@@ -52,8 +54,8 @@ def _pick_kc(bn: int, m: int, K: int,
 
 
 def _adc_kernel(codes_ref, luts_ref, vals_out, idx_out, vals_ref, idx_ref,
-                *, k: int, bq: int, bn: int, K: int, kc: int, n: int,
-                n_steps: int):
+                *, k: int, bq: int, bn: int, m: int, kc: int, n_chunks: int,
+                n: int, n_steps: int):
     j = pl.program_id(1)                       # code-block step
 
     @pl.when(j == 0)
@@ -61,18 +63,28 @@ def _adc_kernel(codes_ref, luts_ref, vals_out, idx_out, vals_ref, idx_ref,
         vals_ref[...] = jnp.full_like(vals_ref, jnp.inf)
         idx_ref[...] = jnp.full_like(idx_ref, NEG_ONE)
 
-    codes = codes_ref[...].astype(jnp.int32)   # [bn, m]
-    lut = luts_ref[...]                        # [bq, m, K]
-    m = codes.shape[1]
+    width = m * kc
+    shift = kc.bit_length() - 1                # kc is a power of two
+    col = jax.lax.broadcasted_iota(jnp.int32, (m, width), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (m, width), 0)
+    rep = (jax.lax.shift_right_logical(col, shift) == sub).astype(jnp.float32)
+    # [bn, m * kc]: code of subspace t // kc at every column t (exact: the
+    # codes are integers below 256 and rep is 0/1)
+    codes = jax.lax.dot_general(
+        codes_ref[...].astype(jnp.int32).astype(jnp.float32), rep,
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    slot = jax.lax.bitwise_and(
+        jax.lax.broadcasted_iota(jnp.int32, (1, width), 1),
+        kc - 1).astype(jnp.float32)            # [1, m * kc]: t % kc
     d = jnp.zeros((bq, bn), jnp.float32)
     # K-chunked one-hot matmul: static python unroll (K/kc steps, so the
-    # LUT slice offsets stay compile-time constants)
-    for c0 in range(0, K, kc):
-        sel = (codes[:, :, None] == c0 + jax.lax.broadcasted_iota(
-            jnp.int32, (bn, m, kc), 2)).astype(jnp.float32)
+    # LUT chunk offsets stay compile-time constants)
+    for ci in range(n_chunks):
+        sel = (codes == slot + float(ci * kc)).astype(jnp.float32)
         d = d + jax.lax.dot_general(
-            lut[:, :, c0:c0 + kc], sel,
-            (((1, 2), (1, 2)), ((), ())),
+            luts_ref[ci], sel, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
     rows = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
@@ -102,21 +114,25 @@ def adc_scan_pallas(
     bq: int = 8,
     bn: int = 256,
     kc: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ):
     n_pad, m = codes.shape
     b_pad, _, K = luts.shape
     assert b_pad % bq == 0 and n_pad % bn == 0, (b_pad, n_pad, bq, bn)
-    assert K % kc == 0, (K, kc)
+    assert K % kc == 0 and kc & (kc - 1) == 0, (K, kc)
     n_steps = n_pad // bn
-    kernel = functools.partial(_adc_kernel, k=k, bq=bq, bn=bn, K=K, kc=kc,
-                               n=n, n_steps=n_steps)
+    n_chunks = K // kc
+    # [K/kc, b, m*kc]: chunk ci, column j*kc + c holds LUT[:, j, ci*kc + c]
+    luts = luts.reshape(b_pad, m, n_chunks, kc).transpose(2, 0, 1, 3) \
+        .reshape(n_chunks, b_pad, m * kc)
+    kernel = functools.partial(_adc_kernel, k=k, bq=bq, bn=bn, m=m, kc=kc,
+                               n_chunks=n_chunks, n=n, n_steps=n_steps)
     vals, idx = pl.pallas_call(
         kernel,
         grid=(b_pad // bq, n_steps),
         in_specs=[
             pl.BlockSpec((bn, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((bq, m, K), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((n_chunks, bq, m * kc), lambda i, j: (0, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
@@ -130,7 +146,7 @@ def adc_scan_pallas(
             pltpu.VMEM((bq, k), jnp.float32),    # running top-C dists
             pltpu.VMEM((bq, k), jnp.int32),      # running top-C rows
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,10 +18,9 @@
                       stream through VMEM in blocks, distances form as
                       one-hot(code) x LUT chunk matmuls on the MXU, and a
                       running top-C accumulator
-                      (``merge_topk_unique_rounds``) folds in-kernel; the
-                      XLA fold is the automatic fallback and the
-                      interpret-mode CI reference the kernel is gated
-                      against.
+                      (``merge_topk_unique_rounds``) folds in-kernel;
+                      compiled on a TPU, interpreted on a CPU backend,
+                      gated against the XLA fold.
 
 ``adc_window_topk`` the candidate-window variant for list-organised
                     indexes (IVF): gathers each candidate's ``m``-byte code
@@ -43,7 +42,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.adc_scan.adc_scan import adc_scan_kernel_path
 
 _FOLD_BUDGET = 32 << 20     # XLA fold: per-block gathered LUT working set
@@ -88,7 +87,7 @@ def adc_scan(codes, luts, *, k: int, block: Optional[int] = None,
     b = luts.shape[0]
     kk = min(int(k), n)
     if use_kernel and n > 0 and b > 0:
-        interpret = INTERPRET if interpret is None else interpret
+        interpret = interpret_mode() if interpret is None else interpret
         return adc_scan_kernel_path(codes, luts, k=kk, block=block,
                                     interpret=interpret)
     flat, offs = _lut_flat(luts)

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 _NEG_INF = -1e30
 
@@ -73,7 +72,7 @@ def decode_attention_pallas(
     lengths: jnp.ndarray,      # [B] valid cache lengths
     *,
     bs: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, G, dh = q.shape
     S = k.shape[1]
@@ -98,7 +97,7 @@ def decode_attention_pallas(
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, dh), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

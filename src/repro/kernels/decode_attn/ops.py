@@ -9,13 +9,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attn.decode_attn import decode_attention_pallas
 
 
 def decode_attention(q, k, v, lengths=None, *, bs: int = 512,
                      interpret: bool | None = None):
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     B, H, dh = q.shape
     S, KV = k.shape[1], k.shape[2]
     assert H % KV == 0

@@ -57,6 +57,7 @@ def _distance_kernel(q_ref, x_ref, qsq_ref, xsq_ref, out_ref, acc_ref, *,
     x = x_ref[...].astype(jnp.float32)          # [bn, bd]
     acc_ref[...] += jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)      # [bq, bn] on the MXU
 
     @pl.when(kd == n_d_steps - 1)
@@ -82,7 +83,7 @@ def distance_matrix_pallas(
     bq: int = 128,
     bn: int = 512,
     bd: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     nq, d = Q.shape
     n = X.shape[0]

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.distance.distance import distance_matrix_pallas
 
 
@@ -40,7 +40,7 @@ def pick_tiles(nq: int, n: int, d: int,
 def distance_matrix(Q, X, *, mode: str = "l2sq",
                     interpret: bool | None = None) -> jnp.ndarray:
     """D[nq, n] distances; mode in {"l2sq", "ip", "cos"}."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     nq, d = Q.shape
     n = X.shape[0]
     bq, bn, bd = pick_tiles(nq, n, d)

@@ -2,13 +2,14 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
 def distance_matrix_ref(Q, X, *, mode: str = "l2sq") -> jnp.ndarray:
     Q = Q.astype(jnp.float32)
     X = X.astype(jnp.float32)
-    cross = Q @ X.T
+    cross = jnp.matmul(Q, X.T, precision=jax.lax.Precision.HIGHEST)
     if mode == "l2sq":
         qsq = jnp.sum(Q * Q, axis=1, keepdims=True)
         xsq = jnp.sum(X * X, axis=1)[None, :]

@@ -23,10 +23,11 @@ Differences from the older ``topk_scan`` kernel it supersedes:
 Grid: (nq/bq, n/bn, d/bd), corpus and contraction axes sequential
 ("arbitrary"), query axis parallel.
 
-Top-k merge: ``merge_topk_rounds`` — k rounds of (min, first-argmin-onehot,
-mask-to-inf) VPU reductions over the (bq, k + bn) concatenation of the
-running state and the fresh tile.  No sort/top_k primitives, so it lowers
-through Mosaic; with bn >> k the MXU matmul still dominates.  Ties break
+Top-k merge: ``repro.kernels.select.merge_topk_rounds`` — k rounds of
+(min, first-argmin, mask-to-inf) VPU reductions over the (bq, k + bn)
+concatenation of the running state and the fresh tile.  No sort/top_k
+primitives, so it lowers through Mosaic; with bn >> k the MXU matmul still
+dominates.  Ties break
 toward the smaller corpus id (the running state precedes the fresh tile and
 ids ascend within a tile), matching ``jax.lax.top_k``.
 """
@@ -40,42 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.kernels.distance.distance import distance_epilogue
-
-NEG_ONE = -1
-
-
-def merge_topk_rounds(cand_d, cand_i, k: int):
-    """The k smallest (dist, id) pairs per row from [bq, m] candidates.
-
-    Returns ([bq, k] dists, [bq, k] ids), ascending, id -1 where fewer than
-    k finite candidates exist.  Pure elementwise/reduction ops (VPU-only).
-    """
-    bq, _ = cand_d.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
-    out_d = jnp.full((bq, k), jnp.inf, jnp.float32)
-    out_i = jnp.full((bq, k), NEG_ONE, jnp.int32)
-
-    def round_fn(t, state):
-        cand_d, out_d, out_i = state
-        mval = jnp.min(cand_d, axis=1, keepdims=True)          # [bq, 1]
-        eq = cand_d == mval
-        first = jnp.cumsum(eq.astype(jnp.int32), axis=1) == 1
-        first = first & eq
-        midx = jnp.sum(jnp.where(first, cand_i, 0), axis=1, keepdims=True)
-        # guard: if mval is inf there is no valid candidate left
-        alive = jnp.isfinite(mval)
-        midx = jnp.where(alive, midx, NEG_ONE)
-        write = col == t
-        out_d = jnp.where(write, mval, out_d)
-        out_i = jnp.where(write, midx, out_i)
-        cand_d = jnp.where(first, jnp.inf, cand_d)
-        return cand_d, out_d, out_i
-
-    _, out_d, out_i = jax.lax.fori_loop(0, k, round_fn,
-                                        (cand_d, out_d, out_i))
-    return out_d, out_i
+from repro.kernels.select import NEG_ONE, merge_topk_rounds
 
 
 def _stream_topk_kernel(q_ref, x_ref, qsq_ref, xsq_ref, vals_out, idx_out,
@@ -97,6 +64,7 @@ def _stream_topk_kernel(q_ref, x_ref, qsq_ref, xsq_ref, vals_out, idx_out,
     x = x_ref[...].astype(jnp.float32)          # [bn, bd]
     acc_ref[...] += jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)      # [bq, bn] on the MXU
 
     @pl.when(kd == n_d_steps - 1)
@@ -130,7 +98,7 @@ def stream_topk_pallas(
     bq: int = 128,
     bn: int = 1024,
     bd: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ):
     nq, d = Q.shape
     n = X.shape[0]
@@ -162,7 +130,7 @@ def stream_topk_pallas(
             pltpu.VMEM((bq, k), jnp.float32),    # running top-k dists
             pltpu.VMEM((bq, k), jnp.int32),      # running top-k ids
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

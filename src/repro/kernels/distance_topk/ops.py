@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.distance_topk.distance_topk import stream_topk_pallas
 
 _METRIC_TO_MODE = {"euclidean": "l2sq", "angular": "cos", "ip": "ip",
@@ -103,7 +103,7 @@ def stream_topk(Q, X, *, k: int, metric: str = "euclidean",
     kernel change.  ``row_ids`` (optional [n] int32) remaps the returned
     row indices to global ids (-1 for empty / masked-out slots).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     mode = _METRIC_TO_MODE[metric]
     if k < 1:
         raise ValueError(f"k must be >= 1, got {k}")
@@ -140,7 +140,7 @@ def stream_topk_batched(Q, X, *, k: int, metric: str = "euclidean",
     ``materialize=False`` returns device arrays without a host sync, so
     index-layer callers can keep the host transfer off the benchmark clock
     (paper §3.5)."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     mode = _METRIC_TO_MODE[metric]
     if k < 1:
         raise ValueError(f"k must be >= 1, got {k}")

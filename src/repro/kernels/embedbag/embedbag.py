@@ -53,7 +53,7 @@ def embedding_bag_pallas(
     table: jnp.ndarray,       # [V, D]
     *,
     n_bags: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     N = indices.shape[0]
     V, Dm = table.shape

@@ -9,14 +9,14 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.embedbag.embedbag import embedding_bag_pallas
 
 
 def embedding_bag(table, indices, bags, weights=None, *, n_bags: int,
                   assume_sorted: bool = False,
                   interpret: bool | None = None):
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     indices = jnp.asarray(indices, jnp.int32)
     bags = jnp.asarray(bags, jnp.int32)
     if weights is None:

@@ -1,10 +1,11 @@
 """Fused Hamming distance + top-k kernel over packed uint32 codes.
 
 The paper's Q4 finding (Hamming-aware implementations are 2-3x faster) rests
-on popcount distance computation.  TPU mapping: codes live as uint32 lanes;
-a (bq, bn) tile XORs query and corpus words broadcast in VMEM and reduces
-with the VPU's population_count — no MXU involvement, entirely
-bandwidth/VPU bound.  Top-k selection reuses the shared scan-merge helper
+on popcount distance computation.  TPU mapping: the corpus arrives
+word-major ([w, n], corpus rows on the lanes), so each of the w words is
+one (bq, bn) tile: the query word column XORs the corpus word row
+broadcast in VMEM and the VPU's population_count accumulates — no MXU
+involvement, entirely bandwidth/VPU bound.  Top-k selection reuses the shared scan-merge helper
 from the streaming kernel (k rounds of min/argmin per tile).
 
 Grid: (nq/bq, n/bn), corpus axis sequential.
@@ -19,13 +20,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
-from repro.kernels.distance_topk.distance_topk import (NEG_ONE,
-                                                       merge_topk_rounds)
+from repro.kernels.select import NEG_ONE, merge_topk_rounds
 
 
-def _hamming_kernel(q_ref, x_ref, nvalid_ref, vals_ref, idx_ref, *,
+def _hamming_kernel(q_ref, xt_ref, nvalid_ref, vals_ref, idx_ref, *,
                     k: int, bn: int):
     j = pl.program_id(1)
 
@@ -35,10 +33,14 @@ def _hamming_kernel(q_ref, x_ref, nvalid_ref, vals_ref, idx_ref, *,
         idx_ref[...] = jnp.full_like(idx_ref, NEG_ONE)
 
     q = q_ref[...]                                     # [bq, w] uint32
-    x = x_ref[...]                                     # [bn, w] uint32
-    xor = jax.lax.bitwise_xor(q[:, None, :], x[None, :, :])
-    d = jnp.sum(jax.lax.population_count(xor), axis=-1).astype(jnp.float32)
-    bq = d.shape[0]
+    xt = xt_ref[...]                                   # [w, bn] uint32
+    # one (bq, bn) tile per word, popcounts summed as int32 (Mosaic
+    # reduces signed integers only)
+    d = jnp.zeros((q.shape[0], bn), jnp.int32)
+    for wi in range(q.shape[1]):
+        xor = jax.lax.bitwise_xor(q[:, wi:wi + 1], xt[wi:wi + 1, :])
+        d = d + jax.lax.population_count(xor).astype(jnp.int32)
+    d = d.astype(jnp.float32)
     base = j * bn
     ids = base + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
     # mask out padded corpus rows
@@ -52,10 +54,11 @@ def _hamming_kernel(q_ref, x_ref, nvalid_ref, vals_ref, idx_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bq", "bn", "interpret"))
-def hamming_topk_pallas(Q, X, n_valid, *, k: int, bq: int = 64,
-                        bn: int = 512, interpret: bool = True):
+def hamming_topk_pallas(Q, XT, n_valid, *, k: int, bq: int = 64,
+                        bn: int = 512, interpret: bool):
+    """Q [nq, w] and the word-major corpus XT [w, n], both uint32."""
     nq, w = Q.shape
-    n = X.shape[0]
+    n = XT.shape[1]
     assert nq % bq == 0 and n % bn == 0
     grid = (nq // bq, n // bn)
     kernel = functools.partial(_hamming_kernel, k=k, bn=bn)
@@ -64,7 +67,7 @@ def hamming_topk_pallas(Q, X, n_valid, *, k: int, bq: int = 64,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((w, bn), lambda i, j: (0, j)),
             pl.BlockSpec((1, 1), lambda i, j: (0, 0),
                          memory_space=pltpu.SMEM),
         ],
@@ -76,9 +79,9 @@ def hamming_topk_pallas(Q, X, n_valid, *, k: int, bq: int = 64,
             jax.ShapeDtypeStruct((nq, k), jnp.float32),
             jax.ShapeDtypeStruct((nq, k), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(Q, X, n_valid)
+    )(Q, XT, n_valid)
     return vals, idx

@@ -15,9 +15,9 @@
                   * **Pallas kernel** (``use_kernel=True``) — the same fold
                     with the gather DMA'd row-by-row into VMEM scratch, so
                     the gathered rows never round-trip through HBM at all.
-                    The XLA fold is the automatic fallback (and the
-                    interpret-mode CI reference the kernel is gated
-                    against).
+                    Compiled on a TPU, interpreted on a CPU backend; the
+                    XLA fold is the reference it is gated against, and is
+                    taken instead only for an empty window.
 
 Both paths return exactly what ``topk_unique`` over the materialized gather
 returns (``ref.rerank_topk_ref``): masked (-1) candidates never win,
@@ -39,7 +39,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 from repro.kernels.rerank_topk.rerank_topk import rerank_topk_pallas
 
 _FOLD_BUDGET = 32 << 20     # XLA fold: gathered-chunk working set (HBM-ish)
@@ -110,12 +110,14 @@ def _chunk_distances(Q, X, qsq, xsq, cand, bad, row_ids, metric: str):
         d = jnp.sum(jax.lax.population_count(xor),
                     axis=-1).astype(jnp.float32) + pen
     elif metric == "euclidean":
-        cross = jnp.einsum("bcd,bd->bc", x, Q)
+        cross = jnp.einsum("bcd,bd->bc", x, Q,
+                           precision=jax.lax.Precision.HIGHEST)
         pen = jnp.where(bad, jnp.inf, xsq[safe]).astype(jnp.float32)
         d = (qsq - 2.0 * cross) + pen
     else:                                                 # angular
         pen = jnp.where(bad, jnp.inf, 0.0).astype(jnp.float32)
-        d = (1.0 - jnp.einsum("bcd,bd->bc", x, Q)) + pen
+        d = (1.0 - jnp.einsum("bcd,bd->bc", x, Q,
+                           precision=jax.lax.Precision.HIGHEST)) + pen
     ids = cand if row_ids is None else row_ids[safe].astype(jnp.int32)
     return d, jnp.where(bad, -1, ids)
 
@@ -138,9 +140,11 @@ def rerank_topk(Q, X, cand, *, k: int, metric: str, xsq=None, row_ids=None,
     ``block``        candidate-block override; autotuned from the shapes
                      when None (``pick_rerank_block``).
     ``use_kernel``   route through the fused Pallas kernel (the
-                     ``rerank_kernel`` build flag); the XLA fold remains
-                     the automatic fallback for shapes the kernel cannot
-                     take (empty windows).
+                     ``rerank_kernel`` build flag); the XLA fold answers
+                     only the shapes the kernel cannot take (empty
+                     windows).
+    ``interpret``    Pallas interpret mode; None decides from the backend
+                     (:func:`repro.kernels.interpret_mode`).
 
     kk = min(k, C); rows with fewer than kk distinct finite candidates pad
     with (+inf, -1), exactly like ``topk_unique``.
@@ -152,7 +156,7 @@ def rerank_topk(Q, X, cand, *, k: int, metric: str, xsq=None, row_ids=None,
     if metric == "euclidean" and xsq is None:
         raise ValueError("euclidean rerank needs the cached xsq table "
                          "(build-time jnp.sum(X**2, axis=1))")
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     cand = jnp.asarray(cand, jnp.int32)
     b, C = cand.shape
     kk = min(int(k), C)

@@ -37,12 +37,14 @@ def rerank_topk_ref(Q, X, cand, *, k: int, metric: str, xsq=None,
         if xsq is None:
             xsq = jnp.sum(X.astype(jnp.float32) ** 2, axis=1)
         qsq = jnp.sum(Q * Q, axis=1, keepdims=True)
-        cross = jnp.einsum("bcd,bd->bc", x, Q)
+        cross = jnp.einsum("bcd,bd->bc", x, Q,
+                           precision=jax.lax.Precision.HIGHEST)
         pen = jnp.where(bad, jnp.inf, xsq[safe]).astype(jnp.float32)
         d = (qsq - 2.0 * cross) + pen
     else:                                                # angular
         pen = jnp.where(bad, jnp.inf, 0.0).astype(jnp.float32)
-        d = (1.0 - jnp.einsum("bcd,bd->bc", x, Q)) + pen
+        d = (1.0 - jnp.einsum("bcd,bd->bc", x, Q,
+                           precision=jax.lax.Precision.HIGHEST)) + pen
     ids = cand if row_ids is None else row_ids[safe].astype(jnp.int32)
     ids = jnp.where(bad, -1, ids)
     return topk_unique(d, ids, min(k, cand.shape[1]))

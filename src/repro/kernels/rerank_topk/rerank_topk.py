@@ -12,8 +12,9 @@ families; Li et al. 2016).
 This kernel fuses the whole pipeline so gathered rows never round-trip
 through HBM:
 
-  * candidate row ids are scalar-prefetched (SMEM) and drive per-row DMAs
-    of the corpus rows into a [bq, bc, d] VMEM scratch tile;
+  * each (query, candidate) tile brings its own [bq, bc] block of row ids
+    into SMEM, and those ids drive per-row DMAs of the corpus rows into a
+    [bq, bc, d] VMEM scratch tile;
   * distances are computed against the resident query tile in all three
     modes — ``l2sq`` (cached squared norms flow in through the per-candidate
     penalty operand), ``cos`` (dot), ``ham`` (XOR + popcount on packed
@@ -31,10 +32,10 @@ parallel.  Invalidity (masked candidates, traced-knob dead windows) arrives
 pre-folded into the penalty operand as +inf, the same sentinel treatment as
 ``distance_topk``'s xsq row.
 
-Selection: ``merge_topk_unique_rounds`` — bit-identical to the canonical
-``repro.ann.topk.topk_unique`` select (the contract the traced-knob parity
-machinery rests on), built from the same VPU-only min/mask reductions as
-``merge_topk_rounds`` so it lowers through Mosaic.
+Selection: ``repro.kernels.select.merge_topk_unique_rounds`` —
+bit-identical to the canonical ``repro.ann.topk.topk_unique`` select (the
+contract the traced-knob parity machinery rests on), built from VPU-only
+min/mask reductions so it lowers through Mosaic.
 """
 
 from __future__ import annotations
@@ -46,56 +47,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-from repro.kernels.distance_topk.distance_topk import NEG_ONE
-
-_I32_MAX = 2**31 - 1
-
-
-def merge_topk_unique_rounds(cand_d, cand_i, k: int):
-    """k smallest (dist, id) pairs per row with duplicate ids removed.
-
-    Bit-identical to ``topk_unique(cand_d, cand_i, k)``: both order the
-    distinct-id candidate set by (dist, id) ascending — dedupe keeps each
-    id's smallest distance, distance ties break toward the smaller id, and
-    rows with fewer than k finite distinct ids pad with (+inf, -1).  Unlike
-    ``topk_unique`` (lexsort + top_k) this is k rounds of pure
-    elementwise/min reductions, so it runs on the VPU inside a kernel.
-
-    Invalid candidates must carry (+inf, -1) — the rerank wrappers' penalty
-    masking guarantees it.
-    """
-    bq, _ = cand_d.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
-    out_d = jnp.full((bq, k), jnp.inf, jnp.float32)
-    out_i = jnp.full((bq, k), NEG_ONE, jnp.int32)
-
-    def round_fn(t, state):
-        cand_d, out_d, out_i = state
-        mval = jnp.min(cand_d, axis=1, keepdims=True)          # [bq, 1]
-        eq = cand_d == mval
-        # among distance ties, the smallest id wins (topk_unique's order)
-        midx = jnp.min(jnp.where(eq, cand_i, _I32_MAX), axis=1,
-                       keepdims=True)
-        alive = jnp.isfinite(mval)
-        midx = jnp.where(alive, midx, NEG_ONE)
-        write = col == t
-        out_d = jnp.where(write, mval, out_d)
-        out_i = jnp.where(write, midx, out_i)
-        # retire EVERY copy of the selected id, not just the winning one —
-        # this is what collapses duplicates across block boundaries
-        cand_d = jnp.where(alive & (cand_i == midx), jnp.inf, cand_d)
-        return cand_d, out_d, out_i
-
-    _, out_d, out_i = jax.lax.fori_loop(0, k, round_fn,
-                                        (cand_d, out_d, out_i))
-    return out_d, out_i
+from repro.kernels.select import NEG_ONE, merge_topk_unique_rounds
 
 
 def _rerank_kernel(cand_ref, q_ref, qsq_ref, ids_ref, pen_ref, x_hbm,
                    vals_out, idx_out, xg_ref, vals_ref, idx_ref, sem, *,
                    mode: str, k: int, bq: int, bc: int, n_c_steps: int):
-    i = pl.program_id(0)                       # query tile
     j = pl.program_id(1)                       # candidate tile
 
     @pl.when(j == 0)
@@ -103,16 +60,15 @@ def _rerank_kernel(cand_ref, q_ref, qsq_ref, ids_ref, pen_ref, x_hbm,
         vals_ref[...] = jnp.full_like(vals_ref, jnp.inf)
         idx_ref[...] = jnp.full_like(idx_ref, NEG_ONE)
 
-    # gather the candidate rows for this (query, candidate) tile into VMEM
-    # scratch: one row DMA per (query, slot) pair, ids from the
-    # scalar-prefetched (SMEM) row table.  The start()/wait() pairs are
-    # serialized — fine under interpret, but real-HW use wants
-    # double-buffering + in-tile dedupe of repeated rows (ROADMAP).
+    # gather the candidate rows of this tile into VMEM scratch: one row DMA
+    # per (query, slot) pair, row ids from this tile's SMEM block.  The
+    # start()/wait() pairs are serialized; overlapping them is open work
+    # (ROADMAP).
     def _gather(t, carry):
         qi = t // bc
         s = t % bc
-        row = cand_ref[i * bq + qi, j * bc + s]
-        dma = pltpu.make_async_copy(x_hbm.at[row], xg_ref.at[qi, s], sem)
+        dma = pltpu.make_async_copy(x_hbm.at[cand_ref[qi, s]],
+                                    xg_ref.at[qi, s], sem)
         dma.start()
         dma.wait()
         return carry
@@ -124,11 +80,13 @@ def _rerank_kernel(cand_ref, q_ref, qsq_ref, ids_ref, pen_ref, x_hbm,
     pen = pen_ref[...]                          # [bq, bc] (+inf = masked)
     if mode == "ham":
         xor = jax.lax.bitwise_xor(x, q[:, None, :])
-        d = jnp.sum(jax.lax.population_count(xor),
+        # Mosaic reduces signed integers only: sum the popcounts as int32
+        d = jnp.sum(jax.lax.population_count(xor).astype(jnp.int32),
                     axis=-1).astype(jnp.float32) + pen
     else:
         cross = jax.lax.dot_general(
             x, q, (((2,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)  # [bq, bc]
         if mode == "l2sq":
             # pen carries the gathered corpus squared norms (cached xsq)
@@ -162,7 +120,7 @@ def rerank_topk_pallas(
     k: int,
     bq: int = 8,
     bc: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ):
     b, d = Q.shape
     C = cand_rows.shape[1]
@@ -172,19 +130,21 @@ def rerank_topk_pallas(
     xg_dtype = X.dtype if mode == "ham" else jnp.float32
     kernel = functools.partial(_rerank_kernel, mode=mode, k=k, bq=bq, bc=bc,
                                n_c_steps=n_c_steps)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    vals, idx = pl.pallas_call(
+        kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq, d), lambda i, j, *_: (i, 0)),
-            pl.BlockSpec((bq, 1), lambda i, j, *_: (i, 0)),
-            pl.BlockSpec((bq, bc), lambda i, j, *_: (i, j)),
-            pl.BlockSpec((bq, bc), lambda i, j, *_: (i, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((bq, bc), lambda i, j: (i, j),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
+            pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((bq, k), lambda i, j, *_: (i, 0)),
-            pl.BlockSpec((bq, k), lambda i, j, *_: (i, 0)),
+            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, bc, d), xg_dtype),   # gathered candidate rows
@@ -192,15 +152,11 @@ def rerank_topk_pallas(
             pltpu.VMEM((bq, k), jnp.int32),      # running top-k ids
             pltpu.SemaphoreType.DMA(()),
         ],
-    )
-    vals, idx = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, k), jnp.float32),
             jax.ShapeDtypeStruct((b, k), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.ann import distances as D
 from repro.ann.functional import get_functional
+from repro.core import compile_cache
 from repro.core.metrics import recall_from_arrays
 from repro.data import get_dataset
 from repro.launch.knobs import coerce, parse_build, parse_kv
@@ -285,7 +286,7 @@ def stream_loop(eng: Engine, ds, args) -> float:
     return agg
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser()
     p.add_argument("--dataset", default="blobs-euclidean-20000")
     p.add_argument("--algorithm", default="IVF")
@@ -333,12 +334,16 @@ def main(argv=None):
     p.add_argument("--churn-inserts", type=int, default=32,
                    help="rows inserted (and later deleted) per iteration "
                         "in --mode churn")
-    args = p.parse_args(argv)
+    return p.parse_args(argv)
 
+
+def setup(args: argparse.Namespace, ds=None):
+    """``(engine, dataset)`` for parsed ``args``: the served Engine, query
+    knobs applied, over ``ds`` (loaded from ``args.dataset`` when None)."""
     apply_shards(args)
-    ds = get_dataset(args.dataset)
+    if ds is None:
+        ds = get_dataset(args.dataset)
     eng = build_or_restore(args, ds)
-
     spec = eng.spec
     # explicit --query key=value wins over legacy positional --query-args,
     # matching the --build vs --args precedence on the build side
@@ -347,6 +352,13 @@ def main(argv=None):
                            [coerce(a) for a in args.query_args]):
         qparams.setdefault(name, value)
     eng.query_params.update(qparams)
+    return eng, ds
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
+    eng, ds = setup(args)
 
     loop = {"batch": batch_loop, "stream": stream_loop,
             "churn": churn_loop}[args.mode]

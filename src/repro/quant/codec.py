@@ -32,6 +32,7 @@ from typing import Any, Dict, Mapping, Optional, Tuple, Union
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.ann.kmeans import kmeans
@@ -188,7 +189,8 @@ def build_luts(codebooks, Q, metric: str):
     """
     m, K, dsub = codebooks.shape
     Qs = _split_queries(jnp.asarray(Q, jnp.float32), m, dsub)  # [b, m, dsub]
-    cross = jnp.einsum("bjd,jkd->bjk", Qs, codebooks)
+    cross = jnp.einsum("bjd,jkd->bjk", Qs, codebooks,
+                       precision=jax.lax.Precision.HIGHEST)
     if metric == "euclidean":
         qsq = jnp.sum(Qs * Qs, axis=2)               # [b, m]
         csq = jnp.sum(codebooks * codebooks, axis=2)  # [m, K]

@@ -345,6 +345,23 @@ class Engine:
         self.stats["padded"] += Qb.shape[0] - n_live
         return dists, ids, coverage
 
+    def compile(self, Q, **overrides):
+        """Compile the serving program ahead of time for this engine's
+        micro-batch shape and return it (``jax.stages.Compiled``:
+        ``.as_text()`` shows the kernels it runs).  ``Q`` is any query
+        batch of the served width and dtype; only its row shape is used.
+        Per-request ``overrides`` are query params, as in :meth:`search`.
+        """
+        params = dict(self.query_params)
+        params.update(overrides)
+        self._check_caps(params)
+        if self._n_shards:
+            params["shard_ok"] = self._shard_all_ok
+        Q = np.asarray(Q)
+        Qb = jax.ShapeDtypeStruct((self.batch_size,) + Q.shape[1:], Q.dtype)
+        return self._search.lower(self.state, Qb, k=self.k,
+                                  **params).compile()
+
     def _pad_batch(self, Q: np.ndarray) -> np.ndarray:
         pad = self.batch_size - Q.shape[0]
         if pad == 0:

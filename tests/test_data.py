@@ -74,3 +74,54 @@ def test_neighbor_sampler_fanout():
     assert sub["dst"].max() < len(sub["feats"])
     # sampled edges exist in the original graph
     nodes = np.asarray([k for k in range(len(sub["feats"]))])
+
+
+def test_dataset_seed_is_a_stable_digest_of_the_name():
+    """The same name gives the same data in every process: ``hash`` of a
+    str is salted per process, the seed is not."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    from repro.data.synthetic import seed_for
+
+    assert seed_for("blobs-euclidean-1000000-d128") == 1213396170
+    src = Path(__file__).resolve().parents[1] / "src"
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from repro.data.synthetic import seed_for;"
+         "print(seed_for('blobs-euclidean-1000000-d128'))"],
+        env={"PYTHONPATH": str(src), "PYTHONHASHSEED": "12345",
+             "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=120)
+    assert out.stdout.strip() == "1213396170", out.stderr[-2000:]
+
+
+@pytest.mark.parametrize("metric", ["euclidean", "angular"])
+def test_groundtruth_matches_float64_reference(metric):
+    """Ground truth and the jnp scan agree with float64 numpy, on data
+    whose large common offset makes a low-precision cross term misorder
+    neighbours; the matmuls request full fp32 precision (a TPU's default
+    is one bf16 pass)."""
+    import jax
+
+    from repro.ann import distances as D
+
+    rng = np.random.default_rng(7)
+    X = (rng.standard_normal((4000, 128)) + 3.0).astype(np.float32)
+    Q = (rng.standard_normal((40, 128)) + 3.0).astype(np.float32)
+    nbrs, dists = exact_knn(X, Q, 10, metric, corpus_block=1024)
+    X64, Q64 = X.astype(np.float64), Q.astype(np.float64)
+    if metric == "euclidean":
+        ref = np.sqrt(((Q64[:, None, :] - X64[None, :, :]) ** 2).sum(-1))
+    else:
+        Xn = X64 / np.linalg.norm(X64, axis=1, keepdims=True)
+        Qn = Q64 / np.linalg.norm(Q64, axis=1, keepdims=True)
+        ref = 1.0 - Qn @ Xn.T
+    want = np.argsort(ref, axis=1, kind="stable")[:, :10]
+    np.testing.assert_array_equal(nbrs, want)
+    np.testing.assert_allclose(dists, np.take_along_axis(ref, want, 1),
+                               rtol=1e-5, atol=1e-5)
+    fn = D.sq_l2_matrix if metric == "euclidean" else D.angular_matrix
+    hlo = jax.jit(fn).lower(Q, X).as_text()
+    assert "HIGHEST" in hlo

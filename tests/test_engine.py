@@ -228,3 +228,25 @@ def test_engine_recall_gate(small_dataset):
         dists, small_dataset.distances[sel], 10, neighbors=ids)))
     assert rec >= 0.9
     assert eng.stats["queries"] == 320
+
+
+def test_compile_is_the_served_program(small_dataset):
+    """``Engine.compile`` builds the micro-batch program ahead of time:
+    its output for a padded batch equals what ``search`` serves, per-
+    request overrides included, and over-cap overrides are refused."""
+    import jax
+
+    eng = Engine.build("IVF", small_dataset.train, metric="euclidean",
+                       build_params={"n_clusters": 30},
+                       query_params={"n_probes": 4, "max_probes": 8},
+                       k=10, batch_size=16)
+    Q = small_dataset.test[:16]
+    compiled = eng.compile(Q, n_probes=8)
+    assert isinstance(compiled, jax.stages.Compiled)
+    params = dict(eng.query_params, n_probes=8)
+    _, ids = compiled(eng.state, Q, **{k: v for k, v in params.items()
+                                      if k in eng.traced_params})
+    _, want = eng.search(Q, n_probes=8)
+    np.testing.assert_array_equal(np.asarray(ids), want)
+    with pytest.raises(ValueError, match="exceeds"):
+        eng.compile(Q, n_probes=9)

@@ -196,12 +196,13 @@ def test_stream_topk_k_exceeds_corpus():
 @pytest.mark.parametrize("nq,n,w,k", [(8, 256, 4, 5), (17, 300, 8, 10),
                                       (64, 512, 25, 32)])
 def test_hamming_kernel(nq, n, w, k):
-    from repro.kernels.hamming import hamming_topk, hamming_topk_ref
+    from repro.kernels.hamming import (hamming_topk, hamming_topk_ref,
+                                       word_major)
 
     rng = np.random.default_rng(w)
     Q = rng.integers(0, 2**32, (nq, w), dtype=np.uint64).astype(np.uint32)
     X = rng.integers(0, 2**32, (n, w), dtype=np.uint64).astype(np.uint32)
-    v, i = hamming_topk(Q, X, k=k, bn=128)
+    v, i = hamming_topk(Q, word_major(X), k=k, bn=128)
     rv, ri = hamming_topk_ref(jnp.asarray(Q), jnp.asarray(X), k=k)
     np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
     # integer distances tie often; compare distance multisets per row

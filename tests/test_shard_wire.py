@@ -39,6 +39,26 @@ def test_roundtrip_is_idempotent(codec):
 
 
 @pytest.mark.parametrize("codec", CODECS)
+def test_snap_equals_wire_roundtrip(codec):
+    """``snap`` gives exactly the values a peer decodes from the wire, for
+    finite, infinite and id-masked entries alike — bf16 rounding ties
+    (low half-word 0x8000, to even) and negatives included."""
+    rng = np.random.default_rng(2)
+    d = (np.abs(rng.standard_normal(512)) * 300.0).astype(np.float32)
+    bits = d.view(np.uint32)
+    for i, low in enumerate((0x8000, 0x7FFF, 0x8001)):
+        bits[1 + i::7] = (bits[1 + i::7] & 0xFFFF0000) | low
+    d[::5] *= -1
+    d[::17] = np.inf
+    ids = np.where(np.arange(512) % 13 == 0, -1, 5).astype(np.int32)
+    lo, hi = _scale(d)
+    want = _rt(d, codec, lo, hi, ids=jnp.asarray(ids))
+    got = np.asarray(wire.snap(jnp.asarray(d), codec, lo, hi,
+                               jnp.asarray(ids)))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("codec", CODECS)
 def test_encode_is_monotone(codec):
     """d1 <= d2 implies wire(d1) <= wire(d2): quantized-domain merge order
     can only differ from exact order inside a tie bucket."""
