@@ -32,7 +32,9 @@ needs — one process, one pump thread, many resident indexes:
   * **latency accounting** — every request's submit-to-answer latency
     lands in a :class:`~repro.serve.metrics.ServeMetrics` histogram
     (p50/p95/p99 per tenant and overall), the numbers the
-    ``bench_serving`` CI gate enforces.
+    ``bench_serving`` CI gate enforces.  While a JAX profile is captured,
+    each request's queue wait and each micro-batch's service are spans
+    of :mod:`repro.serve.tracing`.
   * **fault tolerance** — transient shard faults retry with exponential
     backoff and deterministic jitter
     (:class:`~repro.serve.retry.RetryPolicy`); degraded sharded answers
@@ -59,6 +61,7 @@ import numpy as np
 
 from repro.serve import checkpoint as _ckpt
 from repro.serve import faults as _faults
+from repro.serve import tracing
 from repro.serve.engine import Engine, Ticket, _override_key
 from repro.serve.errors import (AdmissionError, EngineClosed,
                                 EngineDegraded, RetriesExhausted)
@@ -125,7 +128,6 @@ class AsyncEngine:
         # transient faults (ShardFault etc.) retry under this policy;
         # RetryPolicy(max_attempts=1) disables retrying
         self.retry = retry if retry is not None else RetryPolicy()
-        self.last_service_s = 0.0     # most recent micro-batch device+host
         self._queue: deque = deque()
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -367,13 +369,9 @@ class AsyncEngine:
                    or r.ticket._deadline > now + delay_s for r in live)
 
     def _serve(self, batch: list) -> None:
-        """One micro-batch through the tenant's fixed-shape trace.
-
-        Transient faults (:class:`~repro.serve.errors.TransientFault`,
-        e.g. a shard raising mid-search) retry under ``self.retry`` with
-        exponential backoff and deterministic jitter, but only while some
-        live ticket's deadline can still be met; exhausted budgets fail
-        the batch's tickets with :class:`RetriesExhausted`."""
+        """One micro-batch through the tenant's fixed-shape trace: its
+        expired requests are answered as timeouts, the rest served as
+        one ``repro.pump.batch`` span, each with its queue span."""
         eng = self.engines[batch[0].tenant]
         t0 = time.perf_counter()
         # re-check deadlines at service time (they may have lapsed between
@@ -388,6 +386,24 @@ class AsyncEngine:
                 live.append(r)
         if not live:
             return
+        with tracing.batch() as batch_id, tracing.span(
+                "repro.pump.batch", rows=len(live), tenant=live[0].tenant,
+                **live[0].overrides):
+            if batch_id is not None:
+                for r in live:
+                    tracing.record("repro.pump.queue",
+                                   r.ticket._submitted * 1e9, t0 * 1e9,
+                                   batch=batch_id, ticket=int(r.ticket))
+            self._serve_live(eng, live)
+
+    def _serve_live(self, eng: Engine, live: list) -> None:
+        """Answer ``live`` in one device call on ``eng``.
+
+        Transient faults (:class:`~repro.serve.errors.TransientFault`,
+        e.g. a shard raising mid-search) retry under ``self.retry`` with
+        exponential backoff and deterministic jitter, but only while some
+        live ticket's deadline can still be met; exhausted budgets fail
+        the batch's tickets with :class:`RetriesExhausted`."""
         tenant = live[0].tenant
         token = int(live[0].ticket)   # keys the deterministic jitter
         attempt = 0
@@ -397,7 +413,8 @@ class AsyncEngine:
                 Qb = np.stack([r.q for r in live])
                 dists, ids, coverage = eng._run_padded(
                     eng._pad_batch(Qb), len(live), live[0].overrides)
-                dists, ids = np.asarray(dists), np.asarray(ids)
+                with tracing.span("repro.engine.fetch"):
+                    dists, ids = np.asarray(dists), np.asarray(ids)
                 break
             except Exception as e:                  # noqa: BLE001
                 if self.retry.retryable(e) \
@@ -428,7 +445,6 @@ class AsyncEngine:
                     self.metrics.count("failed", tenant=r.tenant)
                 return
         done = time.perf_counter()
-        self.last_service_s = done - t0
         self.metrics.count("batches", tenant=tenant)
         self.metrics.count("padded", eng.batch_size - len(live),
                            tenant=tenant)

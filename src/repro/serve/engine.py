@@ -59,6 +59,7 @@ from repro.serve.checkpoint import (ARCHIVE_VERSION,          # noqa: F401
                                     load_state, save_state)
 from repro.serve.errors import DeadlineExceeded
 from repro.serve import faults as _faults
+from repro.serve import tracing
 
 
 class Ticket(int):
@@ -247,7 +248,7 @@ class Engine:
                               if self._n_shards else None)
         self.last_coverage = 1.0     # min coverage of the last search()
         self.stats = {"queries": 0, "batches": 0, "padded": 0,
-                      "device_time_s": 0.0, "inserts": 0, "deletes": 0,
+                      "inserts": 0, "deletes": 0,
                       "compactions": 0, "compaction_failures": 0,
                       "degraded": 0}
 
@@ -336,10 +337,10 @@ class Engine:
                 coverage = shard_coverage(self.state, mask)
                 self.stats["degraded"] += n_live
             params["shard_ok"] = mask
-        t0 = time.perf_counter()
-        dists, ids = self._search(self.state, Qb, k=self.k, **params)
-        ids = jax.block_until_ready(ids)
-        self.stats["device_time_s"] += time.perf_counter() - t0
+        with tracing.span("repro.engine.launch"):
+            dists, ids = self._search(self.state, Qb, k=self.k, **params)
+        with tracing.span("repro.engine.wait"):
+            ids = jax.block_until_ready(ids)
         self.stats["batches"] += 1
         self.stats["queries"] += n_live
         self.stats["padded"] += Qb.shape[0] - n_live
@@ -387,11 +388,13 @@ class Engine:
         for s in range(0, nq, self.batch_size):
             blk = Q[s:s + self.batch_size]
             live = blk.shape[0]
-            dists, ids, cov = self._run_padded(self._pad_batch(blk), live,
-                                               overrides)
+            with tracing.batch():
+                dists, ids, cov = self._run_padded(self._pad_batch(blk),
+                                                   live, overrides)
+                with tracing.span("repro.engine.fetch"):
+                    ids_out.append(np.asarray(ids[:live]))
+                    dists_out.append(np.asarray(dists[:live]))
             self.last_coverage = min(self.last_coverage, cov)
-            ids_out.append(np.asarray(ids[:live]))
-            dists_out.append(np.asarray(dists[:live]))
         return np.concatenate(dists_out), np.concatenate(ids_out)
 
     # ------------------------------------------------------------- mutation
@@ -566,11 +569,13 @@ class Engine:
                 continue
             Qb = np.stack([q for _, q, _, _ in live_items])
             live = Qb.shape[0]
-            dists, ids, cov = self._run_padded(self._pad_batch(Qb), live,
-                                               live_items[0][3])
-            self._pending = rest
-            ids = np.asarray(ids)
-            dists = np.asarray(dists)
+            with tracing.batch():
+                dists, ids, cov = self._run_padded(self._pad_batch(Qb), live,
+                                                   live_items[0][3])
+                self._pending = rest
+                with tracing.span("repro.engine.fetch"):
+                    ids = np.asarray(ids)
+                    dists = np.asarray(dists)
             for i, (ticket, _, _, _) in enumerate(live_items):
                 ticket._resolve(dists[i], ids[i], coverage=cov)
                 self._results[int(ticket)] = ticket
@@ -655,11 +660,6 @@ class Engine:
         return result
 
     # ------------------------------------------------------------- metadata
-    @property
-    def qps(self) -> float:
-        t = self.stats["device_time_s"]
-        return self.stats["queries"] / t if t > 0 else float("nan")
-
     def index_size_kb(self) -> float:
         return self.state.nbytes() / 1024.0
 
