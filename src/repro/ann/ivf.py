@@ -2,9 +2,9 @@
 analogue from the paper's Table 2, "other: inverted file").
 
 TPU adaptation (DESIGN.md §2.5): inverted lists are stored *cluster-major*
-(corpus sorted by assigned centroid, plus offsets), and a probe reads a
-fixed-size padded window of each probed list with a validity mask — turning
-the CPU's pointer-chasing list scan into dense gathers + masked top-k that
+(corpus sorted by assigned centroid, plus offsets ``starts`` / ``sizes``),
+so every probed list is one contiguous run of corpus rows — turning the
+CPU's pointer-chasing list scan into slice reads + a running top-k that
 lower cleanly onto TPU.
 
 Functional core: ``build(X, n_clusters=...) -> IndexState`` (host k-means,
@@ -19,19 +19,25 @@ query-time knob ``n_probes`` is *traced-or-static*:
     group up to the cap.  This is what lets the serving engine sweep the
     recall/QPS knob without recompilation.
 
-Rerank: the probed candidate window always goes through the shared
-streaming fold (:func:`repro.kernels.rerank_topk.rerank_topk`) — candidate
-blocks folded into a running unique-by-id (dist, id) top-k accumulator, so
-peak rerank memory is O(b * (block + k)) state plus one [b, block, d]
-gathered chunk instead of the materialized O(b * n_probes * max_list * d)
-tensor, which is what lets high-probe configurations run on large corpora
-at all.  ``rerank_block`` overrides the autotuned block; the
-``rerank_kernel`` build flag routes the fold through the fused Pallas
-kernel (candidate rows DMA'd straight into VMEM scratch, distances + the
-running top-k computed in-kernel), with the XLA fold as automatic
-fallback.  The per-list ``scan`` validity mask (traced knob) flows into
-the fold as a kernel input.  ``streaming`` survives as an accepted no-op
-(the fold subsumes it).
+Rerank, two paths chosen by the candidates' structure:
+
+  * runs (the plain fp32 search under the ``rerank_kernel`` build flag):
+    :func:`repro.kernels.rerank_topk.rerank_lists` takes each probed
+    list's start and size and the traced ``n_probes`` / ``scan``, and its
+    kernel copies the lists as chunked slices straight into VMEM; chunks
+    past a list's end or the scan budget, and probes past ``n_probes``,
+    are never read;
+  * row ids (the fold without the flag, ``live=`` / ``id_map=``, and the
+    quantized builds' ADC survivors): a fixed ``max_probes x max_list``
+    window of row ids with a validity mask goes through the shared
+    streaming fold (:func:`repro.kernels.rerank_topk.rerank_topk`) —
+    candidate blocks folded into a running unique-by-id (dist, id) top-k,
+    peak memory O(b * (block + k)) plus one [b, block, d] gathered chunk —
+    or, under ``rerank_kernel``, its Pallas kernel, one row DMA per slot.
+
+Both return the same ids bit for bit over the same window.
+``rerank_block`` overrides the autotuned block (the list kernel's chunk).
+``streaming`` survives as an accepted no-op (the fold subsumes it).
 """
 
 from __future__ import annotations
@@ -49,7 +55,7 @@ from repro.ann.functional import (FunctionalSpec, IndexState, prepare_points,
 from repro.ann.kmeans import kmeans
 from repro.core.interface import FunctionalANN
 from repro.core.registry import register
-from repro.kernels.rerank_topk import rerank_topk
+from repro.kernels.rerank_topk import rerank_lists, rerank_topk
 
 
 # --------------------------------------------------------------- functional
@@ -139,9 +145,7 @@ def search(state: IndexState, Q, *, k: int, n_probes=1, scan=None,
         over the canonically-sorted ADC prefix — bit-identical to the
         static window (the ``topk_unique`` contract).
 
-    The rerank is the shared streaming fold
-    (:func:`repro.kernels.rerank_topk.rerank_topk`, Pallas-fused under the
-    ``rerank_kernel`` build flag), whose select is canonical on the
+    Both rerank paths (module docstring) select canonically on the
     (id, dist) set exactly like ``topk_unique`` — so traced-mode masking
     (which shifts candidate positions) is bit-identical to the static path
     regardless of distance ties.
@@ -172,11 +176,21 @@ def search(state: IndexState, Q, *, k: int, n_probes=1, scan=None,
     #    masked (traced knob) so one trace serves every probe count <= P
     cd = D.sq_l2_matrix(Q, state["centers"])             # [b, C]
     _, probes = jax.lax.top_k(-cd, P)                    # [b, P]
-    probe_live = jnp.arange(P, dtype=jnp.int32) < n_probes       # [P]
-    # 2. padded window gather of each probed list, entries past the traced
-    #    scan budget masked (same treatment as the probe mask)
     starts = state["starts"][probes]                     # [b, P]
     sizes = state["sizes"][probes]                       # [b, P]
+    if quant is None and live is None and id_map is None \
+            and state.static.get("rerank_kernel", False):
+        # 2a. each probed list is one run of the cluster-major corpus: the
+        #     kernel reads the runs as slices, probes past n_probes and
+        #     rows past the scan budget never leave HBM
+        return rerank_lists(
+            Q, state["X"], starts, sizes, k=k, metric=state.metric,
+            ids=state["ids"], xsq=state.arrays.get("xsq"),
+            n_probes=n_probes, scan=scan, window=M,
+            block=state.static.get("rerank_block"))
+    # 2b. padded window of each probed list, entries past the traced scan
+    #     budget masked (same treatment as the probe mask)
+    probe_live = jnp.arange(P, dtype=jnp.int32) < n_probes       # [P]
     offs = jnp.arange(M, dtype=jnp.int32)                # [M]
     cand = starts[..., None] + offs[None, None, :]       # [b, P, M]
     valid = offs[None, None, :] < sizes[..., None]
@@ -195,8 +209,8 @@ def search(state: IndexState, Q, *, k: int, n_probes=1, scan=None,
     rids = state["ids"] if id_map is None \
         else id_map.astype(jnp.int32)[state["ids"]]
     # 3. exact distances on the candidate set: the shared streaming fold
-    #    (optionally the fused Pallas kernel), probe/scan validity masks
-    #    flowing in as the fold's mask input
+    #    (optionally the fused Pallas kernel over the row ids), probe/scan
+    #    validity masks flowing in as the fold's mask input
     return rerank_topk(
         Q, state["X"], cand, k=k, metric=state.metric,
         xsq=state.arrays.get("xsq"), row_ids=rids, valid=valid,

@@ -17,7 +17,8 @@ Kernels:
                    SMEM id blocks into VMEM scratch + distance + running
                    unique-by-id top-k, so the [b, C, d] gathered candidate
                    tensor never exists in HBM (every algorithm's
-                   verification hot path)
+                   verification hot path); IVF's probed lists, runs of
+                   its cluster-major corpus, are copied as slices instead
     adc_scan/      compressed-domain ADC scan: per-query LUTs resident in
                    VMEM, packed uint8 codes streamed in blocks, distances
                    as one-hot x LUT matmuls on the MXU, running top-C fold

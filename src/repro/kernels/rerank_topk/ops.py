@@ -1,9 +1,10 @@
 """Public wrappers for the fused candidate-rerank primitive.
 
-``rerank_topk``   ONE entry point for every candidate-rerank call site in
-                  the suite (LSH schemes, RPForest, IVF, the Hamming
-                  indexes): a [b, C] window of candidate row ids is reduced
-                  to the k best distinct ids without ever materializing the
+``rerank_topk``   the entry point for a window of arbitrary row ids (LSH
+                  schemes, RPForest, the Hamming indexes, quantized IVF's
+                  ADC survivors, IVF over tombstones or remapped ids): a
+                  [b, C] window of candidate row ids is reduced to the k
+                  best distinct ids without ever materializing the
                   [b, C, d] gathered tensor.  Two device paths with
                   identical select semantics:
 
@@ -18,6 +19,12 @@
                     Compiled on a TPU, interpreted on a CPU backend; the
                     XLA fold is the reference it is gated against, and is
                     taken instead only for an empty window.
+
+``rerank_lists``  the entry point for runs of a cluster-major corpus
+                  (IVF's probed lists): per (query, probe) a start and a
+                  size, and the kernel copies each run as chunked slices,
+                  so a list costs a few DMAs instead of one per row and
+                  the padding past its end is never read.
 
 Both paths return exactly what ``topk_unique`` over the materialized gather
 returns (``ref.rerank_topk_ref``): masked (-1) candidates never win,
@@ -34,16 +41,24 @@ contraction.
 
 from __future__ import annotations
 
+import collections
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import interpret_mode
-from repro.kernels.rerank_topk.rerank_topk import rerank_topk_pallas
+from repro.kernels.rerank_topk.rerank_topk import (rerank_lists_pallas,
+                                                   rerank_topk_pallas)
 
 _FOLD_BUDGET = 32 << 20     # XLA fold: gathered-chunk working set (HBM-ish)
 _KERNEL_BUDGET = 4 << 20    # kernel: [bq, bc, d] VMEM gather scratch
+_LIST_BUDGET = 8 << 20      # list kernel: two [bq, bc, d] row chunks
+
+#: trace-time count of the rerank path each trace took: ``"rows"`` for a
+#: window of row ids (:func:`rerank_topk`), ``"lists"`` for runs of a
+#: cluster-major corpus (:func:`rerank_lists`).  Tests reset and read it.
+PATH_COUNTS: "collections.Counter[str]" = collections.Counter()
 
 
 def pick_rerank_block(b: int, C: int, d: int, k: int, *,
@@ -75,6 +90,18 @@ def _pick_kernel_block(bq: int, C: int, d: int, k: int,
     bc = block if block else pick_rerank_block(
         bq, C, d, k, budget=_KERNEL_BUDGET)
     return max(8, min(int(bc), 1024, _ceil_to(C, 8)))
+
+
+def _pick_list_block(bq: int, d: int) -> int:
+    """Rows of a list-kernel chunk: the largest power of two (128..1024)
+    whose two [bq, bc, d] f32 chunk buffers fit ``_LIST_BUDGET``.  Longer
+    chunks mean fewer steps of the select per list: 1024 rows took 2.3 ms
+    a 64-query SIFT-1M batch against 2.8 at 512 and 3.8 at 256 (TPU v5e,
+    8 probes)."""
+    bc = 1024
+    while bc > 128 and 2 * bq * bc * d * 4 > _LIST_BUDGET:
+        bc //= 2
+    return bc
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -157,6 +184,7 @@ def rerank_topk(Q, X, cand, *, k: int, metric: str, xsq=None, row_ids=None,
         raise ValueError("euclidean rerank needs the cached xsq table "
                          "(build-time jnp.sum(X**2, axis=1))")
     interpret = interpret_mode() if interpret is None else interpret
+    PATH_COUNTS["rows"] += 1
     cand = jnp.asarray(cand, jnp.int32)
     b, C = cand.shape
     kk = min(int(k), C)
@@ -216,4 +244,87 @@ def _rerank_kernel_path(Q, X, qsq, xsq, cand, bad, row_ids, metric: str,
         _pad_rows(_pad_cols(ids, Cp, -1), bp, -1),
         _pad_rows(_pad_cols(pen, Cp, jnp.inf), bp, jnp.inf),
         X, mode=mode, k=kk, bq=bq, bc=bc, interpret=interpret)
+    return vals[:b], idx[:b]
+
+
+def rerank_lists(Q, X, starts, sizes, *, k: int, metric: str, ids, xsq=None,
+                 n_probes=None, scan=None, window: int,
+                 block: Optional[int] = None,
+                 interpret: Optional[bool] = None):
+    """(dists [b, kk], ids [b, kk]) over runs of a cluster-major corpus.
+
+    Query i's candidates are the rows ``[starts[i, p], starts[i, p] +
+    sizes[i, p])`` of ``X`` for each probe p, cut to the first ``window``
+    rows (and to ``scan``, a traced budget, when given); probes at or past
+    the traced ``n_probes`` hold none.  ``ids [n]`` gives each row's output
+    id, ``xsq [n]`` its cached squared norm (euclidean).  The answer is
+    the row kernel's over the same window (``rerank_topk`` with
+    ``cand = starts + arange(window)``, the rest masked): kk = min(k, P *
+    window), ids bit-identical, rows with too few candidates padded with
+    (+inf, -1).
+
+    The kernel reads each run in 128-aligned chunks of ``block`` rows
+    (1024 at d <= 128, fewer at larger d): per (query, chunk) a few
+    power-of-two slices of the run's rows and one copy each of the
+    chunk's norms and ids, the next chunk's copies in flight while this
+    one is scored.  Chunks past a run's end or the scan budget, and dead
+    probes, are never read.
+    """
+    if metric not in ("euclidean", "angular"):
+        raise ValueError(f"list rerank scores euclidean or angular, "
+                         f"not {metric!r}")
+    if metric == "euclidean" and xsq is None:
+        raise ValueError("euclidean rerank needs the cached xsq table "
+                         "(build-time jnp.sum(X**2, axis=1))")
+    interpret = interpret_mode() if interpret is None else interpret
+    PATH_COUNTS["lists"] += 1
+    b, P = starts.shape
+    n = X.shape[0]
+    kk = min(int(k), P * window)
+    bq = 8
+    bc = block if block else _pick_list_block(bq, X.shape[1])
+    bc = min(_ceil_to(bc, 128), _ceil_to(window + 127, 128))
+    n_chunks = -(-(window + 127) // bc)
+
+    starts = jnp.asarray(starts, jnp.int32)
+    lens = jnp.minimum(jnp.asarray(sizes, jnp.int32), window)
+    if scan is not None:
+        lens = jnp.minimum(lens, jnp.maximum(scan, 1))
+    if n_probes is not None:
+        lens = jnp.where(jnp.arange(P) < n_probes, lens, 0)
+    bp = _ceil_to(b, bq)
+    starts = _pad_rows(starts, bp, 0)
+    lens = _pad_rows(lens, bp, 0)
+    # the plan of each query tile: how many (probe, chunk) items hold a
+    # live row for any of its queries, then those items, p * n_chunks + c
+    chunks = jnp.where(lens > 0, -(-(starts % 128 + lens) // bc), 0)
+    chunks = chunks.reshape(bp // bq, bq, P).max(axis=1)     # [tiles, P]
+    live = (jnp.arange(n_chunks)[None, None, :] < chunks[..., None])
+    live = live.reshape(bp // bq, P * n_chunks)
+    items = jnp.argsort(~live, axis=1, stable=True).astype(jnp.int32)
+    plan = jnp.concatenate(
+        [jnp.sum(live, axis=1, keepdims=True, dtype=jnp.int32), items], 1)
+    plan = jnp.repeat(plan, bq, axis=0)                      # [bp, 1 + T]
+
+    # norms and ids as rows of 128 lanes, padded so a chunk's lane rows
+    # never run past the end
+    rows = -(-n // 128) + bc // 128
+
+    def lane_rows(a, dtype):
+        a = jnp.asarray(a, dtype)
+        return jnp.pad(a, (0, rows * 128 - n)).reshape(rows, 128)
+
+    Q = jnp.asarray(Q, jnp.float32)
+    if metric == "euclidean":
+        mode = "l2sq"
+        qsq = jnp.sum(Q * Q, axis=1, keepdims=True)
+        xsq = lane_rows(xsq, jnp.float32)
+    else:                               # cos mode reads no norms
+        mode = "cos"
+        qsq = jnp.zeros((b, 1), jnp.float32)
+        xsq = jnp.zeros((8, 128), jnp.float32)
+    vals, idx = rerank_lists_pallas(
+        starts, lens, plan, _pad_rows(Q, bp, 0), _pad_rows(qsq, bp, 0.0),
+        X, xsq, lane_rows(ids, jnp.int32), mode=mode, k=kk, bq=bq, bc=bc,
+        n_chunks=n_chunks, interpret=interpret)
     return vals[:b], idx[:b]

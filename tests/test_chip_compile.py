@@ -84,6 +84,26 @@ def test_rerank_topk_compiles(shape):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("metric", ["euclidean", "angular"])
+def test_rerank_lists_compiles(shape, metric):
+    """The list-slice rerank at the IVF cell's shapes: 64 queries, 8 traced
+    probes of lists up to 4,162 rows (the longest of 1,000 k-means lists
+    over SIFT-1M).  Starts and sizes are runtime values, so the kernel's
+    copies are compiled for any start, 8-aligned or not."""
+    from repro.kernels.rerank_topk import rerank_lists
+
+    hlo = _compiled_hlo(
+        lambda Q, X, starts, sizes, ids, xsq, n_probes: rerank_lists(
+            Q, X, starts, sizes, k=10, metric=metric, ids=ids,
+            xsq=xsq if metric == "euclidean" else None, n_probes=n_probes,
+            window=4162, interpret=False),
+        shape((64, D), jnp.float32), shape((N, D), jnp.float32),
+        shape((64, 8), jnp.int32), shape((64, 8), jnp.int32),
+        shape((N,), jnp.int32), shape((N,), jnp.float32),
+        shape((), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
 def test_adc_scan_compiles(shape):
     from repro.kernels.adc_scan import adc_scan
 

@@ -11,6 +11,12 @@ materialized / fold / kernel in every mode, hamming distances too
 (integer popcounts); float distances agree to the ulp — blocking changes
 the dot shapes XLA vectorizes over.
 
+List level: IVF's probed lists are runs of its cluster-major corpus, and
+the list-slice path (``rerank_lists``) reads them as slices; it returns
+the row path's ids bit for bit over the same window, at the edges of the
+run layout (a list longer than a chunk, an empty list, a list ending at
+row n-1, starts off the 8-row tiles) and under the traced knobs.
+
 Algorithm level: all six candidate-rerank algorithms (IVF, HyperplaneLSH,
 E2LSH, RPForest, BitsamplingAnnoy, MultiIndexHashing) are pinned
 materialized == fold == kernel per algorithm, and the kernel path keeps
@@ -25,9 +31,9 @@ import jax.numpy as jnp
 from repro.ann import functional
 from repro.ann.functional import get_functional, search_sweep
 from repro.ann.topk import topk_unique
-from repro.kernels.rerank_topk import (merge_topk_unique_rounds,
-                                       pick_rerank_block, rerank_topk,
-                                       rerank_topk_ref)
+from repro.kernels.rerank_topk import (PATH_COUNTS, merge_topk_unique_rounds,
+                                       pick_rerank_block, rerank_lists,
+                                       rerank_topk, rerank_topk_ref)
 
 METRICS = ("euclidean", "angular", "hamming")
 
@@ -222,6 +228,124 @@ def test_algorithm_rerank_paths_agree(name, request):
     np.testing.assert_array_equal(np.asarray(if_), np.asarray(ik),
                                   err_msg=f"{name}: kernel != fold")
     _assert_dists(ds.metric, df, dk)
+
+
+# ------------------------------------------------- list-slice path
+# One corpus for every case, so each metric compiles the list kernel once:
+# n = 1003 (off the 8- and 128-row tiles), lists of odd sizes (unaligned
+# starts), one of 300 rows (over the 128-row chunk), one empty, the last
+# ending at row n-1.
+LIST_SIZES = (37, 0, 300, 45, 9, 131, 1, 77, 66, 91, 12, 234)
+LIST_CASES = {
+    # case: (first probe of every query, n_probes, scan)
+    "long_list": (2, None, None),
+    "empty_list": (1, None, None),
+    "ends_at_n": (len(LIST_SIZES) - 1, None, None),
+    "unaligned_starts": (None, None, None),
+    "traced_n_probes": (None, 2, None),
+    "traced_scan": (2, None, 37),
+}
+
+
+def _list_case(metric, first, b=11, P=4, d=16, seed=0):
+    rng = np.random.default_rng(seed)
+    sizes = np.asarray(LIST_SIZES, np.int32)
+    n = int(sizes.sum())
+    starts = np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int32)
+    X = rng.standard_normal((n, d)).astype(np.float32)
+    Q = rng.standard_normal((b, d)).astype(np.float32)
+    if metric == "angular":
+        X /= np.linalg.norm(X, axis=1, keepdims=True)
+        Q /= np.linalg.norm(Q, axis=1, keepdims=True)
+    probes = np.stack([rng.permutation(len(sizes))[:P] for _ in range(b)])
+    if first is not None:
+        probes[:, 0] = first
+        probes[:, 1:] = np.where(probes[:, 1:] == first,
+                                 (first + 1) % len(sizes), probes[:, 1:])
+    xsq = jnp.sum(jnp.asarray(X) ** 2, axis=1) \
+        if metric == "euclidean" else None
+    ids = rng.permutation(n).astype(np.int32)
+    return (jnp.asarray(Q), jnp.asarray(X), starts[probes], sizes[probes],
+            jnp.asarray(ids), xsq, int(sizes.max()))
+
+
+@pytest.mark.parametrize("case", sorted(LIST_CASES))
+@pytest.mark.parametrize("metric", ["euclidean", "angular"])
+def test_list_path_matches_row_path(metric, case):
+    """rerank_lists over the probed runs == the row kernel and the oracle
+    over IVF's window (cand = start + arange(window), masked past each
+    list, past n_probes and past the scan budget): ids bit for bit."""
+    first, n_probes, scan = LIST_CASES[case]
+    Q, X, st, sz, ids, xsq, M = _list_case(metric, first)
+    n = X.shape[0]
+    b, P = st.shape
+    if case == "ends_at_n":
+        assert st[0, 0] + sz[0, 0] == n
+    if case == "unaligned_starts":
+        assert np.any(st % 8 != 0) and np.all(sz <= M)
+    offs = np.arange(M)
+    valid = offs < sz[..., None]
+    if n_probes is not None:
+        valid = valid & (np.arange(P) < n_probes)[None, :, None]
+    if scan is not None:
+        valid = valid & (offs < scan)
+    cand = jnp.asarray(np.minimum(st[..., None] + offs, n - 1).reshape(b, -1))
+    kw = dict(k=10, metric=metric, xsq=xsq, row_ids=ids,
+              valid=jnp.asarray(valid.reshape(b, -1)))
+    rd, ri = rerank_topk_ref(Q, X, cand, **kw)
+    _, ki = rerank_topk(Q, X, cand, use_kernel=True, block=128, **kw)
+    traced = (lambda v: None if v is None else jnp.int32(v))
+    ld, li = rerank_lists(Q, X, jnp.asarray(st), jnp.asarray(sz), k=10,
+                          metric=metric, ids=ids, xsq=xsq,
+                          n_probes=traced(n_probes), scan=traced(scan),
+                          window=M, block=128)
+    np.testing.assert_array_equal(np.asarray(ri), np.asarray(li))
+    np.testing.assert_array_equal(np.asarray(ki), np.asarray(li))
+    _assert_dists(metric, rd, ld)
+    if case == "empty_list":            # the empty probe adds no candidate
+        np.testing.assert_array_equal(np.asarray(li) >= 0,
+                                      np.isfinite(np.asarray(ld)))
+
+
+@pytest.fixture
+def path_counter():
+    PATH_COUNTS.clear()
+    yield PATH_COUNTS
+    PATH_COUNTS.clear()
+
+
+PATH_CASES = {
+    # name: (algo, fixture, build params, query params, path taken)
+    "ivf_sift1m_flags": ("IVF", "small_dataset",
+                         {"n_clusters": 20, "rerank_kernel": True},
+                         {"n_probes": 8, "max_probes": 8}, "lists"),
+    "ivf_quantized": ("IVF", "small_dataset",
+                      {"n_clusters": 20, "rerank_kernel": True,
+                       "quantize": {"pq": {"m": 4}}},
+                      {"n_probes": 8, "max_probes": 8}, "rows"),
+    "lsh": ("HyperplaneLSH", "small_angular",
+            {"n_tables": 4, "n_bits": 8, "cap": 32, "rerank_kernel": True},
+            {"n_probes": 3}, "rows"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PATH_CASES))
+def test_engine_rerank_path(name, request, path_counter):
+    """The path is chosen by the candidates' structure: an Engine over
+    the benchmark's IVF build flags (chipbench sift1m-ivf1000) traces the
+    list path; quantized IVF's ADC survivors and LSH's buckets are
+    arbitrary row ids and keep the row path."""
+    from repro.serve import Engine
+
+    algo, fixture, build, query, path = PATH_CASES[name]
+    ds = request.getfixturevalue(fixture)
+    eng = Engine.build(algo, ds.train, metric=ds.metric, build_params=build,
+                       query_params=query, k=10, batch_size=16)
+    path_counter.clear()
+    _, ids = eng.search(ds.test[:20])
+    assert np.asarray(ids).shape == (20, 10)
+    assert path_counter[path] >= 1
+    assert dict(path_counter) == {path: path_counter[path]}
 
 
 # ------------------------------------------------- traced knobs x kernel
